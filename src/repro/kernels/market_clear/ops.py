@@ -52,20 +52,23 @@ def _clear(order, sorted_gseg, seg_start, prices, tenants, seqs,
            strides: Tuple[int, ...], owner, limit, k: int, *,
            health, use_pallas: bool, interpret: bool, block: int):
     n_seg = seg_start.shape[0] - 1
-    aggs = R._prefix_aggregates(order, sorted_gseg, seg_start, prices,
-                                tenants, seqs, n_seg, k)
-    if use_pallas:
-        out = clear_pallas(*aggs, tuple(level_floor), level_off,
-                           strides, owner, limit, block=block,
-                           interpret=interpret)
-    else:
-        out = R.clear_sorted_from_aggs(aggs, tuple(level_floor),
-                                       level_off, strides, owner,
-                                       limit, k)
-    if health is not None:
-        # One shared mask AFTER backend dispatch: non-up leaves get
-        # all-hole slates, floor-only rates, and floor-pressure-only
-        # evicts — identical on both backends by construction.
-        out = R.apply_health_mask(health, *out, tuple(level_floor),
-                                  strides, owner, limit)
+    # one scope over the whole pass on both backends, so the trace
+    # charges the same work to it whatever implements it
+    with jax.named_scope("market_clear"):
+        aggs = R._prefix_aggregates(order, sorted_gseg, seg_start, prices,
+                                    tenants, seqs, n_seg, k)
+        if use_pallas:
+            out = clear_pallas(*aggs, tuple(level_floor), level_off,
+                               strides, owner, limit, block=block,
+                               interpret=interpret)
+        else:
+            out = R.clear_sorted_from_aggs(aggs, tuple(level_floor),
+                                           level_off, strides, owner,
+                                           limit, k)
+        if health is not None:
+            # One shared mask AFTER backend dispatch: non-up leaves get
+            # all-hole slates, floor-only rates, and floor-pressure-only
+            # evicts — identical on both backends by construction.
+            out = R.apply_health_mask(health, *out, tuple(level_floor),
+                                      strides, owner, limit)
     return out

@@ -44,7 +44,9 @@ jitted ``step`` runs one complete market epoch:
      exhausts a possibly truncated slate freezes in-wave resolution and
      waits for the next full re-clear.  Leaves nobody covers fall back to
      the operator.  The loop is a ``lax.while_loop`` (wave count
-     observable via ``state["waves"]``) so the whole step stays jitted.
+     observable via ``state["waves"]``, its claim rounds via
+     ``state["rounds"]`` and its frozen waves via ``state["frozen"]``)
+     so the whole step stays jitted.
 
 **Sorted-book invariant.**  The engine maintains a segment-sorted view
 of the bid table — ``state["order"]`` (slot permutation),
@@ -187,6 +189,11 @@ class BatchEngine:
             # wave count (each while_loop iteration, incl. the final
             # fixpoint-check wave)
             "waves": jnp.zeros((), jnp.int32),
+            # cumulative in-wave claim rounds run, and cumulative waves
+            # whose round loop froze (a truncated slate exhausted, or a
+            # reclaim) and left the rest to the next full re-clear
+            "rounds": jnp.zeros((), jnp.int32),
+            "frozen": jnp.zeros((), jnp.int32),
             # sorted-view instrumentation: cumulative FULL lexsort count
             # (incremental merges don't count — see place)
             "resorts": jnp.zeros((), jnp.int32),
@@ -711,7 +718,11 @@ class BatchEngine:
                     lambda rc: rc[6] & jnp.any(rc[4]) & (rc[7] < K),
                     round_one, rc)
             st["owner"], st["limit"], st["acq_t"], consumed, _, moved, \
-                _, _ = rc
+                go, r = rc
+            # r counts round_one calls (1 when K == 1); a wave that ends
+            # with go False was frozen
+            st["rounds"] = st["rounds"] + r
+            st["frozen"] = st["frozen"] + (~go).astype(jnp.int32)
             # consume winning orders (each OCO set dissolves atomically);
             # a kill keeps the sorted book view valid
             st["price"] = jnp.where(consumed, NEG, st["price"])
@@ -721,8 +732,9 @@ class BatchEngine:
         def cond(carry):
             return carry[2]
 
-        state, release, _ = lax.while_loop(
-            cond, body, (state, release, jnp.asarray(True)))
+        with jax.named_scope("engine_cascade"):
+            state, release, _ = lax.while_loop(
+                cond, body, (state, release, jnp.asarray(True)))
         return state
 
     # ------------------------------------------------------------------
@@ -804,11 +816,14 @@ class BatchEngine:
             state["floor_t"] = tuple(floor_ts)
         # 4) admit new bids (clipped)
         if new_bids is not None:
-            prices = self._clip_bids(state, new_bids["price"],
-                                     new_bids["level"], new_bids["node"])
-            state = dict(self.place(state, prices, new_bids["level"],
-                                    new_bids["node"], new_bids["tenant"],
-                                    new_bids.get("limit")))
+            with jax.named_scope("engine_place"):
+                prices = self._clip_bids(state, new_bids["price"],
+                                         new_bids["level"],
+                                         new_bids["node"])
+                state = dict(self.place(state, prices, new_bids["level"],
+                                        new_bids["node"],
+                                        new_bids["tenant"],
+                                        new_bids.get("limit")))
         # 5) explicit relinquishments + clear/evict/transfer cascade
         release = no_release
         if relinquish is not None:

@@ -121,6 +121,11 @@ SCHEMA: Dict[str, KeySpec] = {
     "t": KeySpec("f32", (), "engine clock, >= 0, monotone across steps"),
     "waves": KeySpec("i32", (),
                      "cumulative cascade wave count, >= 0"),
+    "rounds": KeySpec("i32", (),
+                      "cumulative in-wave claim rounds run, >= 0"),
+    "frozen": KeySpec("i32", (),
+                      "cumulative count of waves whose claim rounds "
+                      "froze for a full re-clear, in [0, waves]"),
     "resorts": KeySpec("i32", (),
                        "cumulative FULL lexsort count (incremental "
                        "view merges don't count), >= 0"),
@@ -164,15 +169,15 @@ FLEET_STATE_KEYS = ("progress", "served", "demanded", "rate_ewma",
 EFFECTS: Dict[str, Dict[str, tuple]] = {
     "repro.market_jax.engine.BatchEngine.step": {
         "reads": ("acq_t", "bills", "blimit", "dropped", "floor",
-                  "floor_t", "head", "health", "level", "limit",
-                  "next_seq", "node", "order", "owner", "price", "rate",
-                  "resorts", "seg_start", "seq", "sorted_gseg", "t",
-                  "tenant", "waves"),
+                  "floor_t", "frozen", "head", "health", "level",
+                  "limit", "next_seq", "node", "order", "owner",
+                  "price", "rate", "resorts", "rounds", "seg_start",
+                  "seq", "sorted_gseg", "t", "tenant", "waves"),
         "writes": ("acq_t", "bills", "blimit", "dropped", "floor",
-                   "floor_t", "head", "level", "limit", "next_seq",
-                   "node", "order", "owner", "price", "rate", "resorts",
-                   "seg_start", "seq", "sorted_gseg", "t", "tenant",
-                   "waves"),
+                   "floor_t", "frozen", "head", "level", "limit",
+                   "next_seq", "node", "order", "owner", "price",
+                   "rate", "resorts", "rounds", "seg_start", "seq",
+                   "sorted_gseg", "t", "tenant", "waves"),
     },
     "repro.market_jax.engine.BatchEngine.place": {
         "reads": ("blimit", "dropped", "head", "level", "next_seq",
@@ -196,45 +201,47 @@ EFFECTS: Dict[str, Dict[str, tuple]] = {
         "writes": ("health",),
     },
     "repro.market_jax.engine.BatchEngine._cascade": {
-        "reads": ("acq_t", "blimit", "floor", "health", "limit",
-                  "order", "owner", "price", "seg_start", "seq",
-                  "sorted_gseg", "tenant", "waves"),
-        "writes": ("acq_t", "limit", "owner", "price", "rate", "tenant",
-                   "waves"),
+        "reads": ("acq_t", "blimit", "floor", "frozen", "health",
+                  "limit", "order", "owner", "price", "rounds",
+                  "seg_start", "seq", "sorted_gseg", "tenant", "waves"),
+        "writes": ("acq_t", "frozen", "limit", "owner", "price", "rate",
+                   "rounds", "tenant", "waves"),
     },
     "repro.market_jax.bridge.BatchMarket.set_retention_limit": {
         "reads": ("acq_t", "bills", "blimit", "dropped", "floor",
-                  "floor_t", "head", "health", "level", "limit",
-                  "next_seq", "node", "order", "owner", "price", "rate",
-                  "resorts", "seg_start", "seq", "sorted_gseg", "t",
-                  "tenant", "waves"),
+                  "floor_t", "frozen", "head", "health", "level",
+                  "limit", "next_seq", "node", "order", "owner",
+                  "price", "rate", "resorts", "rounds", "seg_start",
+                  "seq", "sorted_gseg", "t", "tenant", "waves"),
         "writes": ("acq_t", "bills", "blimit", "dropped", "floor",
-                   "floor_t", "head", "level", "limit", "next_seq",
-                   "node", "order", "owner", "price", "rate", "resorts",
-                   "seg_start", "seq", "sorted_gseg", "t", "tenant",
-                   "waves"),
+                   "floor_t", "frozen", "head", "level", "limit",
+                   "next_seq", "node", "order", "owner", "price",
+                   "rate", "resorts", "rounds", "seg_start", "seq",
+                   "sorted_gseg", "t", "tenant", "waves"),
     },
     "repro.sim.epoch.EpochRunner.epoch": {
         "reads": ("acq_t", "bids_clipped", "bills", "blimit",
                   "cold_cnt", "cold_until", "demanded", "done_at",
                   "dropped", "explicit_relinquish", "floor", "floor_t",
-                  "head", "health", "implicit_relinquish",
+                  "frozen", "head", "health", "implicit_relinquish",
                   "last_checkpoint", "last_scale_down", "last_t",
                   "level", "limit", "next_seq", "node", "order",
                   "orders", "owner", "price", "progress", "rate",
                   "rate_ewma", "reconfig_until", "resorts",
-                  "revoked_by_fault", "seg_start", "seq", "served",
-                  "sorted_gseg", "t", "tenant", "transfers", "waves"),
+                  "revoked_by_fault", "rounds", "seg_start", "seq",
+                  "served", "sorted_gseg", "t", "tenant", "transfers",
+                  "waves"),
         "writes": ("acq_t", "bids_clipped", "bills", "blimit",
                    "cold_cnt", "cold_until", "demanded", "done_at",
                    "dropped", "explicit_relinquish", "floor", "floor_t",
-                   "head", "implicit_relinquish", "last_checkpoint",
-                   "last_scale_down", "last_t", "level", "limit",
-                   "next_seq", "node", "order", "orders", "owner",
-                   "price", "progress", "rate", "rate_ewma",
-                   "reconfig_until", "resorts", "revoked_by_fault",
-                   "seg_start", "seq", "served", "sorted_gseg", "t",
-                   "tenant", "transfers", "waves"),
+                   "frozen", "head", "implicit_relinquish",
+                   "last_checkpoint", "last_scale_down", "last_t",
+                   "level", "limit", "next_seq", "node", "order",
+                   "orders", "owner", "price", "progress", "rate",
+                   "rate_ewma", "reconfig_until", "resorts",
+                   "revoked_by_fault", "rounds", "seg_start", "seq",
+                   "served", "sorted_gseg", "t", "tenant", "transfers",
+                   "waves"),
     },
     "repro.sim.fleet.Fleet.policy": {
         "reads": ("done_at", "last_checkpoint", "last_scale_down",
@@ -377,6 +384,10 @@ def _runtime_checks(engine, state) -> None:
                    "ring cursor head out of [0, capacity)")
     checkify.check(state["dropped"] >= 0, "dropped count negative")
     checkify.check(state["waves"] >= 0, "wave count negative")
+    checkify.check(state["rounds"] >= 0, "claim round count negative")
+    checkify.check((state["frozen"] >= 0)
+                   & (state["frozen"] <= state["waves"]),
+                   "frozen wave count outside [0, waves]")
     checkify.check(state["resorts"] >= 0, "resort count negative")
     checkify.check(state["t"] >= 0, "engine clock negative")
     # ---- sorted book view validity ----

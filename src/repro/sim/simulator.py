@@ -242,27 +242,32 @@ def make_fleet(fcfg: FleetScenarioConfig):
     Tenant mixes reuse ``make_tenants``'s regime scaling on a single
     H100 tree; ``topology_sensitive`` is forced off — the fleet's v1
     fidelity contract is locality-free (sim/fleet.py docstring)."""
+    from repro import obs
     from repro.market_jax.bridge import BatchMarket
     from repro.sim.fleet import Fleet, FleetConfig, params_from_tenants
-    topo = build_cluster({"H100": fcfg.n_leaves}, gpus_per_host=8,
-                         hosts_per_rack=4, racks_per_zone=4)
-    scfg = ScenarioConfig(
-        regime=fcfg.regime, n_h100=fcfg.n_leaves, n_a100=0,
-        duration_s=fcfg.duration_s, tick_s=fcfg.tick_s, seed=fcfg.seed,
-        n_training=fcfg.n_training, n_inference=fcfg.n_inference,
-        n_batch=fcfg.n_batch, controls=fcfg.controls)
-    tenants = make_tenants(scfg, topo)
-    for t in tenants:
-        t.p.topology_sensitive = False
-    cap = 1 << max(11, (2 * fcfg.b_max - 1).bit_length())
-    market = BatchMarket(topo, fcfg.controls, capacity=cap,
-                         n_tenants=len(tenants) + 1, k=fcfg.k,
-                         use_pallas=fcfg.use_pallas,
-                         interpret=fcfg.interpret)
-    fleet = Fleet(FleetConfig(n=len(tenants), b_max=fcfg.b_max,
-                              per_tenant_bids=fcfg.per_tenant_bids),
-                  market.engines["H100"].tree)
-    params = params_from_tenants(tenants, fcfg.duration_s)
+    with obs.span("make_fleet"):
+        topo = build_cluster({"H100": fcfg.n_leaves}, gpus_per_host=8,
+                             hosts_per_rack=4, racks_per_zone=4)
+        scfg = ScenarioConfig(
+            regime=fcfg.regime, n_h100=fcfg.n_leaves, n_a100=0,
+            duration_s=fcfg.duration_s, tick_s=fcfg.tick_s,
+            seed=fcfg.seed, n_training=fcfg.n_training,
+            n_inference=fcfg.n_inference, n_batch=fcfg.n_batch,
+            controls=fcfg.controls)
+        with obs.span("make_tenants"):
+            tenants = make_tenants(scfg, topo)
+        for t in tenants:
+            t.p.topology_sensitive = False
+        cap = 1 << max(11, (2 * fcfg.b_max - 1).bit_length())
+        with obs.span("market_build"):
+            market = BatchMarket(topo, fcfg.controls, capacity=cap,
+                                 n_tenants=len(tenants) + 1, k=fcfg.k,
+                                 use_pallas=fcfg.use_pallas,
+                                 interpret=fcfg.interpret)
+        fleet = Fleet(FleetConfig(n=len(tenants), b_max=fcfg.b_max,
+                                  per_tenant_bids=fcfg.per_tenant_bids),
+                      market.engines["H100"].tree)
+        params = params_from_tenants(tenants, fcfg.duration_s)
     return topo, tenants, market, fleet, params
 
 
