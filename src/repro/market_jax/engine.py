@@ -46,7 +46,10 @@ jitted ``step`` runs one complete market epoch:
      the operator.  The loop is a ``lax.while_loop`` (wave count
      observable via ``state["waves"]``, its claim rounds via
      ``state["rounds"]`` and its frozen waves via ``state["frozen"]``)
-     so the whole step stays jitted.
+     so the whole step stays jitted.  The rounds carry each slate
+     entry's liveness and drop the few slots a round claims from it;
+     a round that claims more than ``TRACKED_CLAIMS`` slots re-gathers
+     it from the bid table (counted in ``state["liveness_gathers"]``).
 
 **Sorted-book invariant.**  The engine maintains a segment-sorted view
 of the bid table — ``state["order"]`` (slot permutation),
@@ -103,6 +106,13 @@ EPSF = R.EPSF
 HEALTH_UP = R.HEALTH_UP
 HEALTH_DRAINING = R.HEALTH_DRAINING
 HEALTH_DOWN = R.HEALTH_DOWN
+
+# claims per round that the cascade drops from the carried slate
+# liveness by compare (the max and the min of the claimed slots); a
+# round that claims more re-gathers the liveness from the consumed-slot
+# table (every round of the benchmark's arrival and steady traffic
+# claims one slot)
+TRACKED_CLAIMS = 2
 
 
 @dataclass(frozen=True)
@@ -194,6 +204,9 @@ class BatchEngine:
             # reclaim) and left the rest to the next full re-clear
             "rounds": jnp.zeros((), jnp.int32),
             "frozen": jnp.zeros((), jnp.int32),
+            # cumulative claim rounds that claimed more than
+            # TRACKED_CLAIMS slots and re-gathered the slate liveness
+            "liveness_gathers": jnp.zeros((), jnp.int32),
             # sorted-view instrumentation: cumulative FULL lexsort count
             # (incremental merges don't count — see place)
             "resorts": jnp.zeros((), jnp.int32),
@@ -632,20 +645,42 @@ class BatchEngine:
             tenant_tab = st["tenant"]
             blimit_tab = st["blimit"]
             cexp = jnp.clip(cands, 0, cap - 1)      # (n_leaves, K')
+            rank = jnp.arange(cands.shape[-1], dtype=jnp.int32)
+
+            def next_alive(alive, consumed, win, prop, gathers):
+                # the slots this round claimed (one winner each) leave
+                # every slate they sit in.  Up to TRACKED_CLAIMS of
+                # them are the max and the min of the winners' slots,
+                # compared against the slate (the pads -2 and cap
+                # match no entry); a round that claimed more
+                # re-gathers the liveness from the consumed table
+                hi = jnp.max(jnp.where(win, prop, -2))
+                lo = jnp.min(jnp.where(win, prop, cap))
+                tracked = jnp.sum(win) <= TRACKED_CLAIMS
+
+                def drop_claimed(live):
+                    return live & (cands != hi) & (cands != lo)
+
+                def regather(_):
+                    return (cands >= 0) & ~consumed[cexp]
+
+                alive = lax.cond(tracked, drop_claimed, regather, alive)
+                return alive, gathers + (~tracked).astype(jnp.int32)
 
             def round_one(rc):
-                (owner_c, limit_c, acq_c, consumed, unresolved, moved,
-                 go, r) = rc
-                # proposal: each unresolved leaf's best not-yet-consumed
-                # slate entry (exact fall-through) — a vectorized
-                # first-hit over the leaf-major slate (contiguous rows)
-                okj = (cands >= 0) & ~consumed[cexp]
-                found = jnp.any(okj, axis=-1)
-                first = jnp.argmax(okj, axis=-1)
+                (owner_c, limit_c, acq_c, consumed, alive, unresolved,
+                 moved, go, r, gathers) = rc
+                # proposal: each unresolved leaf's best live slate entry
+                # (exact fall-through: ``alive`` is the slate entries
+                # no earlier round of the wave consumed) — a vectorized
+                # first-hit over the leaf-major slate, read by compare
+                # and max over its ranks (a gather here costs more)
+                found = jnp.any(alive, axis=-1)
+                first = jnp.argmax(alive, axis=-1)
                 prop = jnp.where(
                     unresolved & found,
-                    jnp.take_along_axis(
-                        cands, first[:, None], axis=-1)[:, 0],
+                    jnp.max(jnp.where(rank == first[:, None], cands, -1),
+                            axis=-1),
                     -1)
                 ps = jnp.clip(prop, 0, cap - 1)
                 # an evicted leaf re-checks its limit against the
@@ -696,33 +731,40 @@ class BatchEngine:
                 # gate the next resolution — only a full re-clear sees
                 # them, so freeze the remaining rounds
                 go = go & ~jnp.any(recl)
-                return (owner_c, limit_c, acq_c, consumed,
+                # K == 1 runs one round per wave: no next round reads it
+                if K > 1:
+                    alive, gathers = next_alive(alive, consumed, win,
+                                                prop, gathers)
+                return (owner_c, limit_c, acq_c, consumed, alive,
                         unresolved & ~moved_r & ~lapsed & ~done,
-                        moved | moved_r, go, r + 1)
+                        moved | moved_r, go, r + 1, gathers)
 
             # early-exit round loop: identical to running all K rounds
             # (a round with nothing unresolved or a frozen wave is a
             # no-op by construction), but steady-state waves resolve in
-            # 1-2 active rounds, so skipping the idle tail saves the
-            # dominant per-round scatter cost.  K=1 keeps the single
+            # 1-2 active rounds, so skipping the idle tail saves whole
+            # rounds (each a scatter-min over the bid table and the
+            # proposals' gathers).  K=1 keeps the single
             # statically-fused round (the loop machinery costs more
-            # than the round it would skip).
+            # than the round it would skip).  Nothing is consumed at
+            # wave start, so every slate entry is alive.
             rc = (st["owner"], st["limit"], st["acq_t"],
-                  jnp.zeros((cap,), jnp.bool_), unresolved0,
+                  jnp.zeros((cap,), jnp.bool_), cands >= 0, unresolved0,
                   jnp.zeros((n_leaves,), jnp.bool_), jnp.asarray(True),
-                  jnp.zeros((), jnp.int32))
+                  jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
             if K == 1:
                 rc = round_one(rc)
             else:
                 rc = lax.while_loop(
-                    lambda rc: rc[6] & jnp.any(rc[4]) & (rc[7] < K),
+                    lambda rc: rc[7] & jnp.any(rc[5]) & (rc[8] < K),
                     round_one, rc)
-            st["owner"], st["limit"], st["acq_t"], consumed, _, moved, \
-                go, r = rc
+            st["owner"], st["limit"], st["acq_t"], consumed, _, _, \
+                moved, go, r, gathers = rc
             # r counts round_one calls (1 when K == 1); a wave that ends
             # with go False was frozen
             st["rounds"] = st["rounds"] + r
             st["frozen"] = st["frozen"] + (~go).astype(jnp.int32)
+            st["liveness_gathers"] = st["liveness_gathers"] + gathers
             # consume winning orders (each OCO set dissolves atomically);
             # a kill keeps the sorted book view valid
             st["price"] = jnp.where(consumed, NEG, st["price"])

@@ -126,6 +126,11 @@ SCHEMA: Dict[str, KeySpec] = {
     "frozen": KeySpec("i32", (),
                       "cumulative count of waves whose claim rounds "
                       "froze for a full re-clear, in [0, waves]"),
+    "liveness_gathers": KeySpec("i32", (),
+                                "cumulative claim rounds that claimed "
+                                "more slots than the engine tracks and "
+                                "re-gathered the slate liveness, in "
+                                "[0, rounds]"),
     "resorts": KeySpec("i32", (),
                        "cumulative FULL lexsort count (incremental "
                        "view merges don't count), >= 0"),
@@ -169,15 +174,17 @@ FLEET_STATE_KEYS = ("progress", "served", "demanded", "rate_ewma",
 EFFECTS: Dict[str, Dict[str, tuple]] = {
     "repro.market_jax.engine.BatchEngine.step": {
         "reads": ("acq_t", "bills", "blimit", "dropped", "floor",
-                  "floor_t", "frozen", "head", "health", "level",
-                  "limit", "next_seq", "node", "order", "owner",
-                  "price", "rate", "resorts", "rounds", "seg_start",
-                  "seq", "sorted_gseg", "t", "tenant", "waves"),
+                  "floor_t", "frozen", "head", "health", "level", "limit",
+                  "liveness_gathers", "next_seq", "node", "order",
+                  "owner", "price", "rate", "resorts", "rounds",
+                  "seg_start", "seq", "sorted_gseg", "t", "tenant",
+                  "waves"),
         "writes": ("acq_t", "bills", "blimit", "dropped", "floor",
                    "floor_t", "frozen", "head", "level", "limit",
-                   "next_seq", "node", "order", "owner", "price",
-                   "rate", "resorts", "rounds", "seg_start", "seq",
-                   "sorted_gseg", "t", "tenant", "waves"),
+                   "liveness_gathers", "next_seq", "node", "order",
+                   "owner", "price", "rate", "resorts", "rounds",
+                   "seg_start", "seq", "sorted_gseg", "t", "tenant",
+                   "waves"),
     },
     "repro.market_jax.engine.BatchEngine.place": {
         "reads": ("blimit", "dropped", "head", "level", "next_seq",
@@ -201,42 +208,44 @@ EFFECTS: Dict[str, Dict[str, tuple]] = {
         "writes": ("health",),
     },
     "repro.market_jax.engine.BatchEngine._cascade": {
-        "reads": ("acq_t", "blimit", "floor", "frozen", "health",
-                  "limit", "order", "owner", "price", "rounds",
+        "reads": ("acq_t", "blimit", "floor", "frozen", "health", "limit",
+                  "liveness_gathers", "order", "owner", "price", "rounds",
                   "seg_start", "seq", "sorted_gseg", "tenant", "waves"),
-        "writes": ("acq_t", "frozen", "limit", "owner", "price", "rate",
-                   "rounds", "tenant", "waves"),
+        "writes": ("acq_t", "frozen", "limit", "liveness_gathers",
+                   "owner", "price", "rate", "rounds", "tenant", "waves"),
     },
     "repro.market_jax.bridge.BatchMarket.set_retention_limit": {
         "reads": ("acq_t", "bills", "blimit", "dropped", "floor",
-                  "floor_t", "frozen", "head", "health", "level",
-                  "limit", "next_seq", "node", "order", "owner",
-                  "price", "rate", "resorts", "rounds", "seg_start",
-                  "seq", "sorted_gseg", "t", "tenant", "waves"),
+                  "floor_t", "frozen", "head", "health", "level", "limit",
+                  "liveness_gathers", "next_seq", "node", "order",
+                  "owner", "price", "rate", "resorts", "rounds",
+                  "seg_start", "seq", "sorted_gseg", "t", "tenant",
+                  "waves"),
         "writes": ("acq_t", "bills", "blimit", "dropped", "floor",
                    "floor_t", "frozen", "head", "level", "limit",
-                   "next_seq", "node", "order", "owner", "price",
-                   "rate", "resorts", "rounds", "seg_start", "seq",
-                   "sorted_gseg", "t", "tenant", "waves"),
+                   "liveness_gathers", "next_seq", "node", "order",
+                   "owner", "price", "rate", "resorts", "rounds",
+                   "seg_start", "seq", "sorted_gseg", "t", "tenant",
+                   "waves"),
     },
     "repro.sim.epoch.EpochRunner.epoch": {
-        "reads": ("acq_t", "bids_clipped", "bills", "blimit",
-                  "cold_cnt", "cold_until", "demanded", "done_at",
-                  "dropped", "explicit_relinquish", "floor", "floor_t",
-                  "frozen", "head", "health", "implicit_relinquish",
-                  "last_checkpoint", "last_scale_down", "last_t",
-                  "level", "limit", "next_seq", "node", "order",
-                  "orders", "owner", "price", "progress", "rate",
+        "reads": ("acq_t", "bids_clipped", "bills", "blimit", "cold_cnt",
+                  "cold_until", "demanded", "done_at", "dropped",
+                  "explicit_relinquish", "floor", "floor_t", "frozen",
+                  "head", "health", "implicit_relinquish",
+                  "last_checkpoint", "last_scale_down", "last_t", "level",
+                  "limit", "liveness_gathers", "next_seq", "node",
+                  "order", "orders", "owner", "price", "progress", "rate",
                   "rate_ewma", "reconfig_until", "resorts",
                   "revoked_by_fault", "rounds", "seg_start", "seq",
                   "served", "sorted_gseg", "t", "tenant", "transfers",
                   "waves"),
-        "writes": ("acq_t", "bids_clipped", "bills", "blimit",
-                   "cold_cnt", "cold_until", "demanded", "done_at",
-                   "dropped", "explicit_relinquish", "floor", "floor_t",
-                   "frozen", "head", "implicit_relinquish",
-                   "last_checkpoint", "last_scale_down", "last_t",
-                   "level", "limit", "next_seq", "node", "order",
+        "writes": ("acq_t", "bids_clipped", "bills", "blimit", "cold_cnt",
+                   "cold_until", "demanded", "done_at", "dropped",
+                   "explicit_relinquish", "floor", "floor_t", "frozen",
+                   "head", "implicit_relinquish", "last_checkpoint",
+                   "last_scale_down", "last_t", "level", "limit",
+                   "liveness_gathers", "next_seq", "node", "order",
                    "orders", "owner", "price", "progress", "rate",
                    "rate_ewma", "reconfig_until", "resorts",
                    "revoked_by_fault", "rounds", "seg_start", "seq",
@@ -388,6 +397,9 @@ def _runtime_checks(engine, state) -> None:
     checkify.check((state["frozen"] >= 0)
                    & (state["frozen"] <= state["waves"]),
                    "frozen wave count outside [0, waves]")
+    checkify.check((state["liveness_gathers"] >= 0)
+                   & (state["liveness_gathers"] <= state["rounds"]),
+                   "liveness gather count outside [0, rounds]")
     checkify.check(state["resorts"] >= 0, "resort count negative")
     checkify.check(state["t"] >= 0, "engine clock negative")
     # ---- sorted book view validity ----

@@ -107,6 +107,10 @@ def test_topk_fixpoint_identical_to_k1(seed):
                     "level": jnp.array(levels), "node": jnp.array(nodes),
                     "tenant": jnp.array(rng.integers(0, 5, n),
                                         jnp.int32)}
+            # padded to one batch shape (tenant -1 = padding), so each
+            # engine compiles its step once, not once per batch size
+            bids = {key: jnp.pad(v, (0, 63 - n), constant_values=-1)
+                    for key, v in bids.items()}
             rel = jnp.array(rng.integers(-1, 256, 6), jnp.int32)
             state, _, bills = eng.step(state, t, bids, None, rel)
             outs.append((np.asarray(state["owner"]).copy(),
