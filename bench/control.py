@@ -4,9 +4,10 @@
     python3 bench/control.py --workload <cell> --seeds 1,2,3,...
 
 For each seed, in one process on the chip: the program replays the
-cell's segment once through the timed entry (``EpochRunner.epoch``,
-after the prefix, from the snapshot), and the plain reference runs the
-same epochs.  It prints, per seed, the compared numbers of
+cell's segment once through the timed entry (the kit's
+``Deployment.epoch``, after the prefix, from the snapshot), and the
+kit's plain reference runs the same epochs.  It prints, per seed, the
+compared numbers of
 
 * ``program``: the program against the float32 reference (the sound
   readings, whose largest over the seeds is a limit's lower reading);
@@ -36,10 +37,9 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> dict:
         if p not in sys.path:
             sys.path.insert(0, p)
     import run as bench_run
-    from lcbench import compare, spec, workload
-    from lcbench.deploy import Deployment
-    from lcbench.reference import Reference
+    from lcbench import spec
     cell = spec.load_cell(args.workload, root)
+    kit = cell.kit
     bench_run.devices_for(cell.chips, require_tpu)
     if require_tpu:
         bench_run.use_compile_cache(root)
@@ -53,9 +53,9 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> dict:
     prog_max, ctl_min = {}, {}
     for seed in seeds:
         t0 = time.perf_counter()
-        params = workload.make_params(cfg, horizon, seed)
+        params = kit.make_params(cfg, horizon, seed)
         if dep is None:
-            dep = Deployment(cfg, horizon, seed, params)
+            dep = kit.Deployment(cfg, horizon, seed, params)
         else:
             dep.set_params(params)
         state = dep.initial
@@ -67,14 +67,14 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT) -> dict:
         prog = dep.host_state(jax.block_until_ready(state))
         del state
         t1 = time.perf_counter()
-        ref = Reference(cfg, params).run(times).state()
+        ref = kit.Reference(cfg, params).run(times).state()
         t2 = time.perf_counter()
-        ctl = Reference(cfg, params, precision="bfloat16").run(
+        ctl = kit.Reference(cfg, params, precision="bfloat16").run(
             times).state()
         t3 = time.perf_counter()
-        p = compare.readings(prog, ref)
-        c = compare.readings(ctl, ref)
-        for k in compare.NAMES:
+        p = kit.readings(prog, ref)
+        c = kit.readings(ctl, ref)
+        for k in kit.NAMES:
             prog_max[k] = max(prog_max.get(k, 0.0), p[k])
             ctl_min[k] = min(ctl_min.get(k, float("inf")), c[k])
         print(json.dumps({"seed": seed, "program": p, "control": c,

@@ -4,10 +4,10 @@
     python3 bench/engine_split.py --workload <cell> --seed <n> \\
         [--seconds 5]
 
-Builds the cell's deployment and snapshot as ``bench/run.py`` does,
-then runs two windows of whole replays from the snapshot, each of at
-least ``--seconds``: one untraced, one under the profiler.  It prints
-one JSON line with
+Builds the cell's deployment through its kit and snapshots it as
+``bench/run.py`` does (``run.build``), then runs two windows of whole
+replays from the snapshot, each of at least ``--seconds``: one
+untraced, one under the profiler.  It prints one JSON line with
 
 * ``epoch_ms`` of both windows (their ratio is the profiler's cost);
 * ``scope_ms``: device ms per epoch under each ``epoch_*`` scope
@@ -16,7 +16,8 @@ one JSON line with
   ``market_clear`` (``lcbench.inner``: nested scopes count in each);
 * ``waves_per_epoch``, ``claim_rounds_per_wave`` and
   ``frozen_wave_share`` from the engine's ``waves``, ``rounds`` and
-  ``frozen`` counters over the traced window;
+  ``frozen`` counters (the deployment's ``counters``) over the traced
+  window;
 * ``host_s``: the program's set-up spans (``repro.obs``);
 * the costliest device ops.
 
@@ -34,13 +35,15 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 
 
-def counters(win, snap, keys=("waves", "rounds", "frozen")) -> dict:
-    """Each engine counter's delta over the window's replays (every
-    replay starts from ``snap``), ``None`` where the program has no
-    such counter."""
-    est0, est1 = snap[0], win.last[0]
-    return {k: win.replays * (int(est1[k]) - int(est0[k]))
-            if k in est0 else None for k in keys}
+def counters(win, snap,
+             keys=("waves", "rounds", "frozen", "liveness_gathers")
+             ) -> dict:
+    """Each counter's delta over the window's replays (every replay
+    starts from ``snap``), ``None`` where the deployment has no such
+    counter."""
+    import run as bench_run
+    d = bench_run.counter_deltas(win.dep, snap, win.last)
+    return {k: win.replays * d[k] if k in d else None for k in keys}
 
 
 def _ratio(a, b):
@@ -60,24 +63,13 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT,
         if p not in sys.path:
             sys.path.insert(0, p)
     import run as bench_run
-    from lcbench import spec, workload
-    from lcbench.deploy import Deployment
+    from lcbench import spec
     cell = spec.load_cell(args.workload, root)
     devs = bench_run.devices_for(cell.chips, require_tpu)
     if require_tpu:
         bench_run.use_compile_cache(root)
     import jax
-    cfg, seg = cell.config, cell.traffic
-    tick = float(cfg["tick_s"])
-    horizon = seg.horizon_ticks * tick
-    params = workload.make_params(cfg, horizon, args.seed)
-    dep = Deployment(cfg, horizon, args.seed, params)
-    state = dep.initial
-    for t in seg.prefix_times(tick):
-        state = dep.epoch(state, t)
-    snap = jax.block_until_ready(state)
-    times = seg.times(tick)
-    jax.block_until_ready(dep.epoch(dep.copy(snap), times[0]))
+    _, dep, snap, times = bench_run.build(cell, args.seed)
 
     plain = bench_run.Window(dep, snap, times)
     plain.run(args.seconds)
@@ -110,18 +102,17 @@ def main(argv=None, require_tpu: bool = True, root: str = ROOT,
     except ImportError:
         out["host_s"] = None
     if trace:
-        from jax.profiler import ProfileData
-        from lcbench import trace as T
         from lcbench.inner import inner_scope_ns
+        from lcbench.trace import find_xplane, reduce_trace
         try:
-            pd = ProfileData.from_file(T.find_xplane(trace_dir))
-            ops, spans = T._op_events(pd), T._host_spans(pd)
+            red = reduce_trace(find_xplane(trace_dir), bench_run.SCOPES,
+                               op_scopes=bench_run.epoch_op_scopes(
+                                   dep, snap, times[0]))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        op_scopes = bench_run.epoch_op_scopes(dep, snap, times[0])
-        red = T.reduce_events(ops, spans, bench_run.SCOPES, op_scopes)
         scope_ns = dict(red.scope_ns)
-        scope_ns.update(inner_scope_ns(ops, spans, op_scopes=op_scopes))
+        scope_ns.update(inner_scope_ns(red.ops, red.spans,
+                                       op_scopes=red.op_scopes))
         out["busy_share"] = red.busy_ns / red.window_ns
         out["scope_ms"] = {k: v * 1e-6 / win.epochs
                            for k, v in sorted(scope_ns.items())}

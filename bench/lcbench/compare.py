@@ -26,7 +26,7 @@ from __future__ import annotations
 import json
 import math
 import os
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -76,16 +76,23 @@ def readings(prog: dict, ref: dict) -> Dict[str, float]:
             "fleet_err": fleet}
 
 
-def load_limits(bench_dir: str, cell: str) -> Dict[str, float]:
-    with open(os.path.join(bench_dir, "limits", f"{cell}.json")) as f:
+def load_limits(bench_dir: str, cell: str,
+                names: Sequence[str] = NAMES) -> Dict[str, float]:
+    """The cell's limits, in ``names`` order (the cell's kit's
+    ``NAMES``); the file lists exactly those numbers."""
+    path = os.path.join(bench_dir, "limits", f"{cell}.json")
+    with open(path) as f:
         d = json.load(f)
-    return {k: float(d[k]["limit"]) for k in NAMES}
+    if set(d) != set(names):
+        raise ValueError(f"{path} lists {sorted(d)}, the kit compares "
+                         f"{sorted(names)}")
+    return {k: float(d[k]["limit"]) for k in names}
 
 
 def judge(values: Dict[str, float], limits: Dict[str, float]
           ) -> Tuple[bool, List[Tuple[str, float, float]]]:
-    """``correct`` and the (name, value, limit) rows, in NAMES order.
-    A number passes when it is at or below its limit."""
-    rows = [(k, values[k], limits[k]) for k in NAMES]
+    """``correct`` and the (name, value, limit) rows, in the limits'
+    order.  A number passes when it is at or below its limit."""
+    rows = [(k, values[k], lim) for k, lim in limits.items()]
     ok = all(v <= lim for _, v, lim in rows)
     return ok, rows

@@ -5,6 +5,9 @@ config + traffic -> ``FleetScenarioConfig`` -> ``make_fleet`` ->
 ``EpochRunner.epoch``.  The clearing backend and the top-K width are
 left at the program's defaults.  The tenant arrays are the harness's
 own (``workload.make_params``); the program receives them as inputs.
+
+A state is the triple ``(engine state, fleet state, market stats)``;
+the harness touches it only through the methods here.
 """
 from __future__ import annotations
 
@@ -13,6 +16,12 @@ from typing import Dict
 import numpy as np
 
 from lcbench.reference import FLEET_KEYS, strides_of
+
+# the engine's counters (market_jax/engine.py) that ``counters`` hands
+# the harness: cascade waves, bids the full table dropped, claim rounds,
+# waves frozen for a full re-clear, rounds that re-gathered the slate
+# liveness
+COUNTER_KEYS = ("waves", "dropped", "rounds", "frozen", "liveness_gathers")
 
 
 class Deployment:
@@ -85,6 +94,25 @@ class Deployment:
         est, fst, stats = state
         return self.runner.epoch(self.params, est, fst, stats,
                                  jnp.float32(t))
+
+    def orders(self, state):
+        """The device scalar counting the bids placed so far."""
+        return state[2]["orders"]
+
+    def counters(self, state) -> dict:
+        """The engine's device int scalars of ``COUNTER_KEYS``, each
+        where the program's state has it."""
+        est = state[0]
+        return {k: est[k] for k in COUNTER_KEYS if k in est}
+
+    def compiled_text(self, state, t: float) -> str:
+        """HLO text of the epoch compiled for ``state`` at ``t``."""
+        import jax.numpy as jnp
+        est, fst, stats = state
+        runner = self.runner
+        lowered = type(runner).epoch.lower(runner, self.params, est, fst,
+                                           stats, jnp.float32(t))
+        return lowered.compile().as_text()
 
     def host_state(self, state) -> dict:
         """The comparable end state, on the host (``reference.state``'s

@@ -43,3 +43,16 @@ def inner_scope_ns(ops: List[Tuple[float, float, str, str]],
         if ns is not None:
             out[s] = ns
     return out
+
+
+def scope_ms(ctx: dict, scope: str) -> Optional[float]:
+    """Device ms per epoch under ``scope`` (nested scopes counted in
+    it), from a metric reader's context: the traced window's raw events
+    in ``ctx["trace"]`` over ``ctx["epochs"]``.  ``None`` where there is
+    no trace or no op on that scope."""
+    red = ctx.get("trace")
+    if red is None or not red.ops or not ctx.get("epochs"):
+        return None
+    ns = inner_scope_ns(red.ops, red.spans, (scope,),
+                        red.op_scopes).get(scope)
+    return None if ns is None else ns * 1e-6 / ctx["epochs"]

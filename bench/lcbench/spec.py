@@ -1,16 +1,22 @@
-"""Cells, configurations and traffic mixes, found by name.
+"""Cells, configurations, traffic mixes and kits, found by name.
 
 ``BENCHMARK.json`` at the checkout's root lists the cells; each cell
 names a configuration (``bench/configs/<name>.json``, located through
 the manifest's ``file``) and a traffic mix
-(``bench/traffic/<name>.json``).  A later cell is a new entry plus new
-data files; nothing here changes for it.
+(``bench/traffic/<name>.json``).  A configuration may name its kit
+(``"kit": "<name>"``, ``bench/kits/<name>.py``; ``fleet`` where it names
+none): the module that binds its deployment's tenant generator, the
+system under test, the plain reference and the compared numbers.  A
+later cell is a new entry plus new files; nothing here changes for it.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -57,11 +63,47 @@ def load_traffic(name: str, bench_dir: str = BENCH_DIR) -> Segment:
     return seg
 
 
+DEFAULT_KIT = "fleet"
+# what a kit binds:
+#   tenant_table(config, horizon_s, seed) -> the population's arrays
+#   make_params(config, horizon_s, seed) -> the arrays the program gets
+#   Deployment(config, horizon_s, seed, params): the system under test,
+#       with initial, copy, epoch, orders, counters, compiled_text,
+#       host_state (and set_params, for bench/control.py)
+#   Reference(config, params, precision="float32"): .run(times) -> self,
+#       .state() -> host_state's layout
+#   readings(prog, ref) -> {name: number}, for every name of NAMES
+KIT_ATTRS = ("tenant_table", "make_params", "Deployment", "Reference",
+             "readings", "NAMES")
+
+
+def load_kit(name: str, bench_dir: str = BENCH_DIR) -> ModuleType:
+    """``<bench_dir>/kits/<name>.py``, loaded by path."""
+    kits = os.path.join(bench_dir, "kits")
+    path = os.path.join(kits, f"{name}.py")
+    if os.path.basename(name) != name or not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(kits)
+                       if f.endswith(".py")) if os.path.isdir(kits) else []
+        raise SystemExit(f"unknown kit {name!r}; known: {known}")
+    mod_name = "lcbench_kit_" + name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered, so that what the kit defines (dataclasses, pickled
+    # objects) can find its module
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    missing = [a for a in KIT_ATTRS if not hasattr(mod, a)]
+    if missing:
+        raise SystemExit(f"kit {name!r} ({path}) lacks {missing}")
+    return mod
+
+
 @dataclass(frozen=True)
 class Cell:
     name: str
     config: dict
     traffic: Segment
+    kit: ModuleType
     chips: int
     end_to_end: List[dict]
     per_layer: List[dict]
@@ -81,9 +123,10 @@ def load_cell(name: str, root: str = ROOT,
     w = cells[name]
     configs = {c["name"]: c for c in man["configs"]}
     cfg = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    bench_dir = os.path.join(root, "bench")
     return Cell(name=name, config=cfg,
-                traffic=load_traffic(w["traffic"],
-                                     os.path.join(root, "bench")),
+                traffic=load_traffic(w["traffic"], bench_dir),
+                kit=load_kit(cfg.get("kit", DEFAULT_KIT), bench_dir),
                 chips=int(w["chips"]),
                 end_to_end=[m for m in man["end_to_end"]
                             if _applies(m, name)],

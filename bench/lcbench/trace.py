@@ -64,12 +64,17 @@ def _shape(event_name: str) -> str:
 
 @dataclass
 class Reduced:
+    """The reduction, and the raw events and instruction -> path map it
+    was made from (``lcbench.inner`` reduces those again per scope)."""
     window_ns: float
     busy_ns: float
     scope_ns: Dict[str, float]
     n_ops: int
     top_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
+    ops: List[Tuple[float, float, str, str]] = field(default_factory=list)
+    spans: List[Tuple[float, float, str]] = field(default_factory=list)
+    op_scopes: Dict[str, str] = field(default_factory=dict)
 
 
 def _merge(intervals: List[Tuple[float, float]]
@@ -132,6 +137,7 @@ def reduce_events(ops: List[Tuple[float, float, str, str]],
     device ops and ``(start_ns, end_ns, name)`` harness spans.  The
     ``bench.window`` span bounds the window (the ops' extent where it
     is absent)."""
+    raw = (ops, spans, op_scopes or {})
     wins = [s for s in spans if s[2] == WINDOW]
     if wins:
         w0 = min(s[0] for s in wins)
@@ -191,4 +197,5 @@ def reduce_events(ops: List[Tuple[float, float, str, str]],
     return Reduced(window_ns=w1 - w0, busy_ns=busy_ns, scope_ns=scope_ns,
                    n_ops=len(ops),
                    top_ops=[(k, v * 1e-9) for k, v in top_ops],
-                   idle_gaps=labelled)
+                   idle_gaps=labelled, ops=raw[0], spans=raw[1],
+                   op_scopes=raw[2])

@@ -9,26 +9,41 @@ run from the root of a checkout on a machine with a TPU.  A run
 1. refuses to run without a TPU (or with fewer chips than the cell
    asks for) and names the device;
 2. keeps JAX's persistent compilation cache at ``<checkout>/.jax_cache``;
-3. generates the tenant mix from ``--seed`` (``lcbench.workload``) and
-   builds the deployment through the program's normal path
-   (``lcbench.deploy``);
+3. generates the tenant mix from ``--seed`` and builds the deployment
+   through the program's normal path, both as the configuration's kit
+   (``bench/kits/<name>.py``, ``lcbench.spec``) says;
 4. runs the epochs before the cell's segment once and snapshots the
-   state (engine, fleet, counters) on the device;
+   state on the device;
 5. warms up with one epoch from a copy of the snapshot; everything so
    far is ``setup_s``;
 6. replays the segment from fresh device copies of the snapshot, back
    to back, while fewer than ``--seconds`` have passed, always
    finishing the replay in progress.  Every number comes from whole
    replays;
-7. compares the end state of the window's last replay with the plain
-   reference (``lcbench.reference``, ``lcbench.compare``), prints each
-   compared number beside its limit on standard error, and prints the
-   result as the last line of standard output.
+7. compares the end state of the window's last replay with the kit's
+   plain reference, number by number (the kit's ``readings``, judged by
+   ``lcbench.compare``), prints each compared number beside its limit
+   on standard error, and prints the result as the last line of
+   standard output.
+
+The harness touches a deployment only through ``initial``, ``copy``,
+``epoch``, ``orders`` (bids placed so far: ``attempted``),
+``counters`` (device int scalars; ``dropped`` counts the bids that
+failed), ``compiled_text`` and ``host_state``.
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` whole replays of at most ``TRACE_SECONDS`` run under the
 profiler and the metrics are the cell's per-layer metrics, each read by
-``bench/metrics/<name>.py``.
+``bench/metrics/<name>.py`` from a context of
+
+* ``epochs``: the traced window's epochs;
+* ``trace``: the ``lcbench.trace.Reduced`` of its profile, the six
+  epoch scopes' self times with the raw ops, harness spans and
+  instruction -> op-name path map they came from (``lcbench.inner``
+  reduces those for any other scope);
+* ``counters``: each of the deployment's counters, its delta over the
+  window;
+* ``config``: the cell's configuration.
 """
 import time
 
@@ -111,18 +126,24 @@ class Stages:
         return " ".join(f"{n}={t:.3f}" for n, t in self.marks)
 
 
+def counter_deltas(dep, start, end) -> dict:
+    """Each of the deployment's counters: its value in state ``end``
+    less that in state ``start``, both read in one ``jax.device_get``."""
+    import jax
+    a, b = jax.device_get((dep.counters(start), dep.counters(end)))
+    return {k: int(b[k]) - int(a[k]) for k in b}
+
+
 class Window:
     """Whole replays of the segment from fresh copies of a snapshot."""
 
     def __init__(self, dep, snapshot, times):
         self.dep, self.snap, self.times = dep, snapshot, times
-        est0 = snapshot[0]
-        self.waves0 = int(est0["waves"])
-        self.dropped0 = int(est0["dropped"])
-        self.orders0 = int(snapshot[2]["orders"])
+        self.orders0 = int(dep.orders(snapshot))
         self.lat, self.bids = [], []
         self.replays, self.replay_s = 0, 0.0
-        self.waves, self.dropped = 0, 0
+        # each counter's delta, summed over the replays
+        self.counters = dict.fromkeys(dep.counters(snapshot), 0)
         self.last = None
 
     def replay(self):
@@ -145,14 +166,14 @@ class Window:
                 jax.block_until_ready(state)
             t1 = time.perf_counter()
             with TraceAnnotation("bench.counter_read"):
-                orders = int(state[2]["orders"])
+                orders = int(dep.orders(state))
             self.lat.append(t1 - t0)
             self.bids.append(orders - prev)
             prev = orders
         self.replay_s += t1 - t_first
         with TraceAnnotation("bench.counter_read"):
-            self.waves += int(state[0]["waves"]) - self.waves0
-            self.dropped += int(state[0]["dropped"]) - self.dropped0
+            for k, v in counter_deltas(dep, self.snap, state).items():
+                self.counters[k] += v
         self.replays += 1
         self.last = state
 
@@ -182,42 +203,23 @@ def load_reader(name: str):
 
 def epoch_op_scopes(dep, snapshot, t: float) -> dict:
     """HLO instruction -> op-name path of the compiled epoch."""
-    import jax.numpy as jnp
     from lcbench.trace import hlo_op_scopes
-    est, fst, stats = snapshot
-    runner = dep.runner
-    lowered = type(runner).epoch.lower(runner, dep.params, est, fst,
-                                       stats, jnp.float32(t))
-    return hlo_op_scopes(lowered.compile().as_text())
+    return hlo_op_scopes(dep.compiled_text(snapshot, t))
 
 
-def run(argv=None, *, require_tpu: bool = True, fault=None,
-        root: str = ROOT) -> dict:
-    """One run; returns the result object (``main`` prints it).
-    ``fault(dep)``, for the harness's own tests, may break the timed
-    path after the deployment is built."""
-    args = parse(argv)
-    for p in (os.path.join(root, "src"), BENCH_DIR):
-        if p not in sys.path:
-            sys.path.insert(0, p)
-    from lcbench import compare, spec, workload
-    cell = spec.load_cell(args.workload, root)
-    devs = devices_for(cell.chips, require_tpu)
+def build(cell, seed: int, stage=lambda name: None, fault=None):
+    """The cell's deployment built through its kit from ``seed``, the
+    epochs before the segment run once and snapshotted, one segment
+    epoch warmed up: ``(params, dep, snapshot, segment times)``.
+    ``stage(name)`` marks the end of each step; ``fault(dep)``, for the
+    harness's own tests, may break the timed path once it is built."""
     import jax
-    dev = devs[0]
-    log(f"device: platform={dev.platform} kind={dev.device_kind} "
-        f"count={len(devs)}")
-    log(f"compile cache: {use_compile_cache(root)}")
-    stage = Stages()
-    limits = compare.load_limits(os.path.join(root, "bench"), cell.name)
-    from lcbench.deploy import Deployment
-    from lcbench.reference import Reference
-    cfg, seg = cell.config, cell.traffic
+    kit, cfg, seg = cell.kit, cell.config, cell.traffic
     tick = float(cfg["tick_s"])
     horizon = seg.horizon_ticks * tick
-    params = workload.make_params(cfg, horizon, args.seed)
+    params = kit.make_params(cfg, horizon, seed)
     stage("tenants")
-    dep = Deployment(cfg, horizon, args.seed, params)
+    dep = kit.Deployment(cfg, horizon, seed, params)
     stage("deployment")
     if fault is not None:
         fault(dep)
@@ -229,9 +231,33 @@ def run(argv=None, *, require_tpu: bool = True, fault=None,
     times = seg.times(tick)
     warm = dep.epoch(dep.copy(snap), times[0])
     jax.block_until_ready(warm)
-    int(warm[2]["orders"])
-    del warm
+    int(dep.orders(warm))
     stage("warm-up")
+    return params, dep, snap, times
+
+
+def run(argv=None, *, require_tpu: bool = True, fault=None,
+        root: str = ROOT) -> dict:
+    """One run; returns the result object (``main`` prints it).
+    ``fault(dep)``, for the harness's own tests, may break the timed
+    path after the deployment is built."""
+    args = parse(argv)
+    for p in (os.path.join(root, "src"), BENCH_DIR):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    from lcbench import compare, spec
+    cell = spec.load_cell(args.workload, root)
+    devs = devices_for(cell.chips, require_tpu)
+    import jax
+    dev = devs[0]
+    log(f"device: platform={dev.platform} kind={dev.device_kind} "
+        f"count={len(devs)}")
+    log(f"compile cache: {use_compile_cache(root)}")
+    stage = Stages()
+    kit, cfg, seg = cell.kit, cell.config, cell.traffic
+    limits = compare.load_limits(os.path.join(root, "bench"), cell.name,
+                                 kit.NAMES)
+    params, dep, snap, times = build(cell, args.seed, stage, fault)
     setup_s = time.perf_counter() - T_START
     log(f"setup_s={setup_s:.6f} prefix_epochs={seg.first} "
         f"segment_epochs={seg.epochs} stages: {stage.text()}")
@@ -262,8 +288,8 @@ def run(argv=None, *, require_tpu: bool = True, fault=None,
               "count": len(devs), "memory_peak_bytes": peak}
 
     result = {"correct": False, "attempted": int(sum(win.bids)),
-              "failed": int(win.dropped), "metrics": {},
-              "device": device}
+              "failed": int(win.counters.get("dropped", 0)),
+              "metrics": {}, "device": device}
     if args.trace:
         from lcbench.trace import find_xplane, reduce_trace
         try:
@@ -275,7 +301,7 @@ def run(argv=None, *, require_tpu: bool = True, fault=None,
         device["busy_s"] = red.busy_ns * 1e-9
         device["window_s"] = red.window_ns * 1e-9
         ctx = {"epochs": win.epochs, "trace": red,
-               "counters": {"waves": win.waves}}
+               "counters": dict(win.counters), "config": cfg}
         for m in cell.per_layer:
             v = load_reader(m["name"])(ctx)
             if v is not None:
@@ -299,12 +325,12 @@ def run(argv=None, *, require_tpu: bool = True, fault=None,
         + f" | bids: {win.bids[:len(times)]}")
 
     prog = dep.host_state(win.last)
-    del win, snap, state, dep
-    ref = Reference(cfg, params)
+    del win, snap, dep
+    ref = kit.Reference(cfg, params)
     t0 = time.perf_counter()
-    ref.run(seg.prefix_times(tick) + times)
+    ref.run(seg.prefix_times(float(cfg["tick_s"])) + times)
     log(f"reference: {time.perf_counter() - t0:.3f} s")
-    values = compare.readings(prog, ref.state())
+    values = kit.readings(prog, ref.state())
     correct, rows = compare.judge(values, limits)
     result["correct"] = bool(correct)
     # JSON has no infinity: an infinite reading (an inf or NaN on one

@@ -1,9 +1,10 @@
 """Helpers and fixtures of the benchmark harness's CPU tests: the
 harness and the program on ``sys.path``, one tiny configuration, a
-checkout-shaped directory with it under both traffic mixes, and
-``bench/run.py`` as a module.  (Test modules import the fixtures by
-name; a ``conftest.py`` here would shadow the repository's own
-``tests/conftest.py``, which its tests import as ``conftest``.)"""
+checkout-shaped directory with it under both traffic mixes (and a way
+to add cells to a copy of it), and ``bench/run.py`` as a module.
+(Test modules import the fixtures by name; a ``conftest.py`` here would
+shadow the repository's own ``tests/conftest.py``, which its tests
+import as ``conftest``.)"""
 import json
 import os
 import shutil
@@ -38,12 +39,14 @@ def tiny_config() -> dict:
 @pytest.fixture(scope="session")
 def tiny_root(tmp_path_factory):
     """A checkout-shaped directory: BENCHMARK.json with the tiny cells,
-    the tiny config, the real traffic mixes and limits."""
+    the tiny config, the real traffic mixes, kits and limits."""
     root = tmp_path_factory.mktemp("tiny_root")
     bench = root / "bench"
     (bench / "configs").mkdir(parents=True)
     (bench / "limits").mkdir()
-    shutil.copytree(os.path.join(BENCH, "traffic"), bench / "traffic")
+    for d in ("traffic", "kits"):
+        shutil.copytree(os.path.join(BENCH, d), bench / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (bench / "configs" / "tiny.json").write_text(json.dumps(tiny_config()))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         man = json.load(f)
@@ -58,6 +61,36 @@ def tiny_root(tmp_path_factory):
         shutil.copy(os.path.join(BENCH, "limits", f"{full}.json"),
                     bench / "limits" / f"{c}.json")
     return str(root)
+
+
+def add_cell(root, cell: str, config: dict, traffic: str,
+             limits: dict) -> None:
+    """Add to the checkout at ``root`` the configuration ``config``
+    (``bench/configs/<its name>.json``), a cell of it under ``traffic``
+    and the cell's ``limits`` file."""
+    bench = os.path.join(root, "bench")
+    name = config["name"]
+    with open(os.path.join(bench, "configs", f"{name}.json"), "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(bench, "limits", f"{cell}.json"), "w") as f:
+        json.dump(limits, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    man["configs"].append(dict(man["configs"][0], name=name,
+                               file=f"bench/configs/{name}.json"))
+    man["workloads"].append(dict(man["workloads"][0], name=cell,
+                                 config=name, traffic=traffic))
+    for m in man["per_layer"]:
+        m["workloads"].append(cell)
+    with open(path, "w") as f:
+        json.dump(man, f)
+
+
+def tiny_limits(cell: str) -> dict:
+    """The limits file a tiny cell is judged by."""
+    with open(os.path.join(BENCH, "limits", f"{LIMITS_OF[cell]}.json")) as f:
+        return json.load(f)
 
 
 @pytest.fixture

@@ -1,10 +1,13 @@
 """Device time under the engine step's nested scopes
 (``lcbench.inner``), the outer attribution of the recorded v5e trace
-pinned, ``bench/engine_split.py``'s counters, and the ``fleet_build_s``
-reader."""
+pinned, ``bench/engine_split.py``'s counters, the readers of the engine
+scopes and counters, and the ``fleet_build_s`` reader."""
+import dataclasses
 import gzip
 import json
 import os
+
+import pytest
 
 from bench_helpers import (BENCH, tiny_config, bench_run,  # noqa: F401
                            tiny_root)
@@ -110,7 +113,7 @@ def test_counters_are_replay_deltas(bench_run):
     win.replay()
     win.replay()
     got = engine_split.counters(win, snap)
-    assert got["waves"] == win.waves > 0
+    assert got["waves"] == win.counters["waves"] > 0
     assert got["waves"] <= got["rounds"] <= dep.engine.k * got["waves"]
     assert 0 <= got["frozen"] <= got["waves"]
     assert engine_split.counters(win, snap, ("no_such",)) == \
@@ -131,6 +134,34 @@ def test_split_runs_at_tiny_size(bench_run, tiny_root):
     assert 0 <= out["frozen_wave_share"] <= 1
     assert set(out["epoch_ms"]) == {"untraced", "traced"}
     assert out["host_s"]["make_fleet"] > 0
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("place_ms", 8), ("cascade_ms", 50), ("clear_ms", 18)])
+def test_engine_scope_readers(bench_run, metric, want):
+    """The readers of the engine scopes reduce the traced window's raw
+    events, per epoch, as ``lcbench.inner`` does."""
+    from lcbench.trace import reduce_events
+    red = reduce_events(_nested_ops(), _spans(), OUTER)
+    read = bench_run.load_reader(metric)
+    assert read({"epochs": 2, "trace": red}) == pytest.approx(
+        want * 1e-6 / 2)
+    # the outer reduction alone, without its events, gives nothing
+    bare = dataclasses.replace(red, ops=[], spans=[], op_scopes={})
+    assert read({"epochs": 2, "trace": bare}) is None
+
+
+@pytest.mark.parametrize("metric,counters,want", [
+    ("claim_rounds_per_wave", {"waves": 8, "rounds": 124, "frozen": 1},
+     15.5),
+    ("frozen_wave_share", {"waves": 8, "rounds": 124, "frozen": 1},
+     0.125),
+    ("claim_rounds_per_wave", {"waves": 0, "rounds": 0}, None),
+    ("frozen_wave_share", {"waves": 8}, None)])
+def test_engine_counter_readers(bench_run, metric, counters, want):
+    got = bench_run.load_reader(metric)({"epochs": 2,
+                                         "counters": counters})
+    assert got == want
 
 
 def test_fleet_build_s_reads_the_make_fleet_span(bench_run):

@@ -72,8 +72,8 @@ def test_cell_files_exist_and_load(man, cell):
     c = spec.load_cell(cell, ROOT, man)
     assert c.traffic.first + c.traffic.epochs <= c.traffic.horizon_ticks
     assert c.config["precision"] == "float32"
-    lim = compare.load_limits(BENCH, cell)
-    assert set(lim) == set(compare.NAMES)
+    lim = compare.load_limits(BENCH, cell, c.kit.NAMES)
+    assert list(lim) == list(c.kit.NAMES)
     for m in c.per_layer:
         assert os.path.exists(os.path.join(BENCH, "metrics",
                                            f"{m['name']}.py"))
@@ -106,12 +106,12 @@ def test_segment_lies_in_its_phase(man, cell):
     """``arrival`` replays every tenant's arrival from t = 0; ``steady``
     starts after the last arrival and ends before the first deadline,
     on the configuration's own population."""
-    from lcbench import spec, workload
+    from lcbench import spec
     c = spec.load_cell(cell, ROOT, man)
     tick = float(c.config["tick_s"])
     horizon = c.traffic.horizon_ticks * tick
-    p = workload.tenant_table(c.config, horizon,
-                              int(c.config["population_seed"]))
+    p = c.kit.tenant_table(c.config, horizon,
+                           int(c.config["population_seed"]))
     times = c.traffic.times(tick)
     if c.traffic.name == "arrival":
         assert times[0] == 0.0 and p["arrival_s"].max() <= times[-1]

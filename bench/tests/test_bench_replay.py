@@ -28,6 +28,30 @@ def test_two_replays_from_one_snapshot_agree(bench_run):
     assert int(snap[2]["orders"]) == win.orders0
 
 
+def test_window_counters_are_deltas_of_the_state(bench_run):
+    """The readers' counters are each of the deployment's counters, end
+    of the replay less the snapshot, summed over the replays."""
+    from lcbench import workload
+    from lcbench.deploy import COUNTER_KEYS, Deployment
+    cfg = tiny_config()
+    params = workload.make_params(cfg, 1200.0, 11)
+    dep = Deployment(cfg, 1200.0, 11, params)
+    state = dep.initial
+    for t in (0.0, 60.0):
+        state = dep.epoch(state, t)
+    snap = jax.block_until_ready(state)
+    win = bench_run.Window(dep, snap, [120.0, 180.0])
+    win.replay()
+    end, start = win.last[0], snap[0]
+    assert set(win.counters) == set(COUNTER_KEYS)
+    assert win.counters == {k: int(end[k]) - int(start[k])
+                            for k in COUNTER_KEYS}
+    assert win.counters["waves"] > 0
+    win.replay()
+    assert win.counters == {k: 2 * (int(end[k]) - int(start[k]))
+                            for k in COUNTER_KEYS}
+
+
 def test_generator_matches_the_programs_tenant_mix():
     """``lcbench.workload`` is a copy of the program's scenario
     generator; at the same seed both give the same arrays."""

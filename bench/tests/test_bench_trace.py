@@ -1,10 +1,11 @@
 """The trace reduction: busy union, self time per scope with nested
-ops counted once, and idle gaps labelled by the harness span."""
+ops counted once, and idle gaps labelled by the harness span; a traced
+run's readers."""
 import os
 
 import pytest
 
-from bench_helpers import BENCH
+from bench_helpers import BENCH, bench_run, tiny_root  # noqa: F401
 
 SCOPES = ("epoch_policy", "epoch_step", "epoch_after_step",
           "epoch_advance")
@@ -111,3 +112,31 @@ def test_recorded_tpu_trace():
         (red.window_ns - red.busy_ns) * 1e-9 + 1e-12
     assert red.idle_gaps[0][0] in ("bench.sync", "bench.snapshot_copy")
     assert all(lbl.startswith("bench.") for lbl, _ in red.idle_gaps)
+
+
+def _cpu_ops(pd):
+    """The CPU's XLA ops, from the host threads that run them: on the
+    CPU no device plane holds an ``XLA Ops`` line."""
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name, "")
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines if line.name.startswith("tf_XLA")
+            for e in line.events]
+
+
+def test_traced_run_reports_every_per_layer_metric(bench_run, tiny_root,
+                                                   monkeypatch):
+    """A ``--trace 1`` run end to end at a tiny size: every reader gets
+    its context (trace, counters, config) and reports.  The numbers are
+    the CPU's and are not checked."""
+    import json
+    from lcbench import trace as T
+    monkeypatch.setattr(T, "_op_events", _cpu_ops)
+    res = bench_run.run(["--workload", "tiny.arrival", "--seed", "5",
+                         "--seconds", "0.05", "--trace", "1"],
+                        require_tpu=False, root=tiny_root)
+    with open(os.path.join(tiny_root, "BENCHMARK.json")) as f:
+        per_layer = [m["name"] for m in json.load(f)["per_layer"]]
+    assert res["correct"] is True
+    assert sorted(res["metrics"]) == sorted(per_layer)
+    assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
+    assert res["breakdown"]["device_ops"]
